@@ -37,6 +37,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 quick = os.environ.get("REPRO_COMM_QUICK") == "1"
 import json, functools, dataclasses
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.distributed import audit as audit_lib
@@ -50,7 +51,7 @@ from repro.training.train_step import TrainState, train_step
 cfg = get_config("muonbp-960m")
 # keep compile cheap; per-layer comm scales linearly
 cfg = dataclasses.replace(cfg, num_layers=2 if quick else 4)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ctx = sh.make_ctx(cfg, mesh, global_batch=8)
 
 a_params = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
@@ -132,6 +133,9 @@ def run(quick: bool = False) -> list[str]:
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     env["REPRO_COMM_QUICK"] = "1" if quick else "0"
+    # The child measures HLO bytes on 8 host devices; it must never try to
+    # take an accelerator that this process may already hold.
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT], capture_output=True, text=True, env=env,
         timeout=1800,

@@ -65,8 +65,9 @@ def one_device_engine(params):
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import make_engine
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pspecs = jax.tree.map(lambda p: P(*([None] * p.ndim)), params)
     return make_engine(params, pspecs, mesh)
 

@@ -23,6 +23,7 @@ from benchmarks.common import row, timeit_stats
 from repro.configs import get_config
 from repro.core import adamw, block_muon, combine, dion, label_tree, muon, muon_full
 from repro.core.blocking import BlockSpec2D
+from repro.launch.mesh import make_mesh
 from repro.models.model import init_params
 
 
@@ -83,7 +84,7 @@ def run(quick: bool = False) -> list[str]:
 
     from repro.distributed import make_engine
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pspecs = jax.tree.map(lambda p: P(*(None,) * p.ndim), params)
     for sched in ("barrier", "pipelined"):
         engine = make_engine(params, pspecs, mesh)

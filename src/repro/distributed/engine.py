@@ -70,7 +70,6 @@ from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import program as program_lib
@@ -310,12 +309,12 @@ class ShardMapEngine:
             return tuple(results)
 
         body = barrier_body if prog.schedule is None else pipelined_body
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=specs,
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return list(fn(*u_leaves))
 

@@ -141,7 +141,7 @@ def pipeline_vmem_budget(link: str = "ici") -> int:
             f"link must be one of {tuple(PIPELINE_VMEM_RESERVE_BY_LINK)}, "
             f"got {link!r}"
         ) from None
-    return fused.VMEM_BUDGET_BYTES - reserve
+    return fused.VMEM_LIMIT_BYTES - reserve
 
 
 def plan_strategy(shape, backend: str, *, vmem_budget: Optional[int] = None) -> str:
@@ -171,7 +171,7 @@ def plan_strategy(shape, backend: str, *, vmem_budget: Optional[int] = None) -> 
         return "jnp"
     from repro.kernels.newton_schulz import fused
 
-    budget = vmem_budget if vmem_budget is not None else fused.VMEM_BUDGET_BYTES
+    budget = vmem_budget if vmem_budget is not None else fused.VMEM_LIMIT_BYTES
     if fused.fits_vmem(shape, budget=budget):
         return "fused_chain"
     return "tiled"

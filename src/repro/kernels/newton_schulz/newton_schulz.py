@@ -12,12 +12,13 @@ P X). On TPU these map to the MXU with 128x128 tiling; this module provides
 Tiling: grid (M/bm, N/bn, K/bk) with the K dimension innermost ("arbitrary"
 semantics) accumulating into a VMEM scratch tile; block shapes default to
 128x128x512 — MXU-aligned and, at bf16, a (128x512 + 512x128 + 128x128 fp32)
-working set of ~320 KiB, comfortably inside the ~16 MiB/core VMEM with
-double-buffering.
+working set of ~320 KiB, comfortably inside Mosaic's default scoped-VMEM
+limit (16 MiB on v5e) with double-buffering.
 
-This container is CPU-only: kernels are *validated in interpret mode*
-(pl.pallas_call(..., interpret=True) executes the kernel body in Python)
-against ``ref.py``; on a real TPU the same code lowers to Mosaic.
+On a TPU the kernels lower to Mosaic; ``tests/test_chip_compile.py``
+compiles them for a described v5e. Elsewhere they run in interpret mode
+(``pl.pallas_call(..., interpret=True)`` executes the kernel body with
+XLA ops), which is how the CPU tests check them against ``ref.py``.
 
 These tiled kernels remain the fallback path for matrices whose fused
 working set exceeds VMEM; the default kernel path is the single-launch
@@ -37,12 +38,6 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 512
-
-# JAX 0.4.x exposes TPUCompilerParams; newer releases renamed it to
-# CompilerParams. Resolve once so every kernel in this package works on both.
-CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 
 
 def _matmul_kernel(x_ref, y_ref, out_ref, acc_ref, *, n_k: int):
@@ -127,7 +122,7 @@ def matmul(
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -172,7 +167,7 @@ def fma_matmul(
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

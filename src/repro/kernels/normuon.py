@@ -11,11 +11,14 @@ an elementwise broadcast divide over rows each rank already owns.
 
 Two equivalent executions of the same padded math:
 
-  * :func:`neuron_norm` — the fused Pallas kernel: grid over the stack,
-    one ``(1, m_p, n_p)`` block in VMEM per step, row statistics + EMA +
-    normalization in one launch, fp32 internally. Row/lane pads follow the
-    fused-NS convention (multiples of 8 x 128); row mean-squares are
-    computed as ``sum(x*x) * (1/n_true)`` so zero-padding is exact.
+  * :func:`neuron_norm` — the fused Pallas kernel: grid over the stack and
+    over tiles of rows (the statistics are per row, so a tile of whole rows
+    is self-contained), one ``(1, rows, n_p)`` block in VMEM per step, row
+    statistics + EMA + normalization in one launch, fp32 internally. The
+    row tile is sized from the width (:func:`row_tile`), so the kernel
+    compiles at any realistic width. Lanes pad to multiples of 128 and rows
+    to the tile; row mean-squares are computed as ``sum(x*x) * (1/n_true)``
+    so zero-padding is exact.
   * :func:`neuron_norm_reference` — pure jnp on the SAME padded shapes and
     op order, bitwise-identical to the kernel in interpret mode (asserted
     in tests/test_variants.py) and the partitioner-friendly path for
@@ -38,7 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.newton_schulz.newton_schulz import CompilerParams, round_up
+from repro.kernels.newton_schulz.fused import VMEM_LIMIT_BYTES
+from repro.kernels.newton_schulz.newton_schulz import round_up
 
 # Lane width of the statistics blocks: v logically has a single column, but
 # VMEM blocks want a 128-multiple last dim, so the kernel carries the stats
@@ -47,6 +51,26 @@ STAT_LANES = 128
 
 # Additive guard for the RMS-preserving rescale's means (exact-zero updates).
 _TINY = 1e-30
+
+
+
+def _vmem_bytes_per_row(n_p: int) -> int:
+    """Scoped VMEM one row of a tile takes, counted as ``fused.fits_vmem``
+    counts: Pallas double-buffers the fp32 in and out blocks (4 rows of
+    ``n_p`` and 4 of ``STAT_LANES``), and Mosaic keeps up to four more
+    row-sized fp32 temporaries (``x``, ``x * x``, the scaled output and a
+    relayout copy)."""
+    return 4 * (8 * n_p + 4 * STAT_LANES)
+
+
+def row_tile(m: int, n: int) -> int:
+    """Rows per grid step for an ``(m, n)`` matrix: a multiple of 8 whose
+    tile fits ``fused.VMEM_LIMIT_BYTES``, spread evenly over the fewest
+    tiles so the row padding stays under 8 per tile."""
+    cap = max(8, VMEM_LIMIT_BYTES // _vmem_bytes_per_row(round_up(n, 128)) // 8 * 8)
+    m8 = round_up(m, 8)
+    tiles = -(-m8 // cap)
+    return round_up(-(-m8 // tiles), 8)
 
 
 def _norm_math(x, v0, corr, *, beta2, eps, inv_n, refresh):
@@ -66,7 +90,7 @@ def _norm_math(x, v0, corr, *, beta2, eps, inv_n, refresh):
 
 def _neuron_norm_kernel(x_ref, v_ref, corr_ref, out_ref, vout_ref, *,
                         beta2, eps, inv_n, refresh):
-    """One stacked matrix per grid step, everything resident in VMEM."""
+    """One tile of rows of one stacked matrix per grid step, in VMEM."""
     x = x_ref[0].astype(jnp.float32)
     v0 = v_ref[0][:, :1].astype(jnp.float32)
     y, v = _norm_math(x, v0, corr_ref[0, 0], beta2=beta2, eps=eps,
@@ -75,15 +99,16 @@ def _neuron_norm_kernel(x_ref, v_ref, corr_ref, out_ref, vout_ref, *,
     vout_ref[0] = jnp.broadcast_to(v, vout_ref.shape[1:]).astype(vout_ref.dtype)
 
 
-def _pad_operands(x: jax.Array, v: jax.Array):
+def _pad_operands(x: jax.Array, v: jax.Array, rows: int):
     """Tile-align ``(B, m, n)``/``(B, m, 1)`` to ``(B, m_p, n_p)``/``(B, m_p, LANES)``.
 
-    Zero-padding is exact: pad rows carry zero statistics and produce zero
-    outputs (``0 / eps``), and pad columns contribute nothing to the row
-    sums because the mean divides by the TRUE column count.
+    ``m_p`` is a multiple of the row tile ``rows``. Zero-padding is exact:
+    pad rows carry zero statistics and produce zero outputs (``0 / eps``),
+    and pad columns contribute nothing to the row sums because the mean
+    divides by the TRUE column count.
     """
     _, m, n = x.shape
-    mp, np_ = round_up(m, 8), round_up(n, 128)
+    mp, np_ = round_up(m, rows), round_up(n, 128)
     if (mp, np_) != (m, n):
         x = jnp.pad(x, ((0, 0), (0, mp - m), (0, np_ - n)))
     v = jnp.pad(v, ((0, 0), (0, mp - m), (0, STAT_LANES - 1)))
@@ -113,30 +138,35 @@ def neuron_norm(
     if x.ndim != 3 or v.shape != (*x.shape[:-1], 1):
         raise ValueError(f"expected (B, m, n) + (B, m, 1), got {x.shape}/{v.shape}")
     bsz, m, n = x.shape
-    xp, vp, mp, np_ = _pad_operands(x.astype(jnp.float32), v.astype(jnp.float32))
+    tr = row_tile(m, n)
+    xp, vp, mp, np_ = _pad_operands(x.astype(jnp.float32), v.astype(jnp.float32), tr)
     corr2 = jnp.asarray(corr, jnp.float32).reshape(1, 1)
     y, v_new = pl.pallas_call(
         functools.partial(
             _neuron_norm_kernel, beta2=float(beta2), eps=float(eps),
             inv_n=1.0 / float(n), refresh=refresh,
         ),
-        grid=(bsz,),
+        grid=(bsz, mp // tr),
         in_specs=[
-            pl.BlockSpec((1, mp, np_), lambda i: (i, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, mp, STAT_LANES), lambda i: (i, 0, 0),
+            pl.BlockSpec((1, tr, np_), lambda i, r: (i, r, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tr, STAT_LANES), lambda i, r: (i, r, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, mp, np_), lambda i: (i, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, mp, STAT_LANES), lambda i: (i, 0, 0),
+            pl.BlockSpec((1, tr, np_), lambda i, r: (i, r, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tr, STAT_LANES), lambda i, r: (i, r, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, mp, np_), jnp.float32),
             jax.ShapeDtypeStruct((bsz, mp, STAT_LANES), jnp.float32),
         ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(xp, vp, corr2)
     return y[:, :m, :n], v_new[:, :m, :1]
@@ -155,12 +185,14 @@ def neuron_norm_reference(
     """Pure-jnp twin of :func:`neuron_norm` — same padded shapes, same ops.
 
     Runs :func:`_norm_math` per stacked matrix on the identically padded
-    operands, so interpret-mode kernel outputs match bitwise.
+    operands. Every row is computed on its own, so the kernel's row tiles
+    give the same bits (asserted in interpret mode).
     """
     if x.ndim != 3 or v.shape != (*x.shape[:-1], 1):
         raise ValueError(f"expected (B, m, n) + (B, m, 1), got {x.shape}/{v.shape}")
     bsz, m, n = x.shape
-    xp, vp, _, _ = _pad_operands(x.astype(jnp.float32), v.astype(jnp.float32))
+    xp, vp, _, _ = _pad_operands(x.astype(jnp.float32), v.astype(jnp.float32),
+                                 row_tile(m, n))
     corr_f = jnp.asarray(corr, jnp.float32).reshape(1, 1)[0, 0]
     ys, vs = [], []
     for i in range(bsz):

@@ -1,5 +1,10 @@
 """Mesh construction: production pod meshes + `--mesh` spec parsing.
 
+``make_mesh`` is the one place a device mesh is built. Every axis is
+``AxisType.Auto``: the sharding code here relies on the GSPMD partitioner
+(``jax.make_mesh`` alone defaults to ``Explicit`` axes, under which the
+partitioner's implicit resharding is a type error).
+
 ``make_production_mesh`` is a FUNCTION (importing this module never touches
 jax device state). The dry-run launcher forces 512 host platform devices
 *before* importing anything from repro (see launch/dryrun.py lines 1-2).
@@ -15,8 +20,16 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 MESH_AXES = ("pod", "data", "model")
+
+
+def make_mesh(shape, axes, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-partitioned)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def parse_mesh_spec(spec: str) -> tuple[tuple[str, ...], tuple[int, ...]]:
@@ -67,7 +80,7 @@ def make_mesh_from_spec(spec: str) -> jax.sharding.Mesh:
             f"{len(devices)} (set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n} for a host smoke)"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -82,7 +95,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             "the dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before jax initializes"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_local_mesh(model: int | None = None, data: int | None = None,
@@ -98,10 +111,11 @@ def make_local_mesh(model: int | None = None, data: int | None = None,
     if pod:
         if data is None:
             data = n // (model * pod)
-        return jax.make_mesh(
+        return make_mesh(
             (pod, data, model), ("pod", "data", "model"),
             devices=jax.devices()[: pod * data * model],
         )
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"), devices=jax.devices()[: data * model])
+    return make_mesh((data, model), ("data", "model"),
+                     devices=jax.devices()[: data * model])

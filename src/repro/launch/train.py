@@ -47,9 +47,9 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import json
 import sys
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +63,7 @@ from repro.core.muon import StaggerSchedule
 from repro.core.schedule import cosine, wsd
 from repro.data.pipeline import SyntheticLM
 from repro.kernels import dispatch
+from repro.launch import compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.model import init_params
 from repro.obs import (
@@ -120,7 +121,36 @@ def build_optimizer(name, params, *, lr, adam_lr, period, schedule_fn=None,
                    labels), period_eff
 
 
-def main():
+@dataclasses.dataclass
+class TrainResult:
+    """What a run returns to an in-process caller (e.g. ``chip_smoke.py``).
+
+    Per executed step: loss, phase, wall seconds of the step span (device
+    completion included only with ``--obs-block``) and the seconds JAX
+    spent compiling inside it. ``device`` is what JAX reports for the
+    first device. ``state``/``step_fns``/``batch`` are the final train
+    state, the jitted step per phase and the last batch, so a caller can
+    lower or compile a step after the run; drop the result to free them.
+    """
+
+    losses: list
+    phases: list
+    step_s: list
+    compile_s: list
+    device: dict
+    status: str
+    state: Any = None
+    step_fns: dict = None
+    batch: dict = None
+
+
+def device_info() -> dict:
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
+def main(argv=None) -> TrainResult:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="muonbp-960m")
     ap.add_argument("--reduced", action="store_true")
@@ -239,7 +269,8 @@ def main():
                          "named muonbp.<phase>.s<stage>.<gather|ns|writeback>")
     ap.add_argument("--profile-dir", default="/tmp/repro_profile",
                     help="output dir for the --profile-steps trace")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    compile_cache.enable()
 
     variant_name = (args.optimizer_variant
                     if args.optimizer_variant is not None
@@ -274,7 +305,9 @@ def main():
     sinks.append(StdoutSink())
     bus = Bus(sinks)
     set_bus(bus)
-    bus.event("run_start", argv=sys.argv[1:], args=vars(args))
+    device = device_info()
+    bus.event("run_start", argv=sys.argv[1:] if argv is None else list(argv),
+              args=vars(args), device=device)
     # NS launch counters: fires at trace time (per jit specialization),
     # never per executed step — zero hot-path cost.
     dispatch.set_launch_hook(
@@ -415,18 +448,25 @@ def main():
         if args.guard else None
     )
     state = init_train_state(params, optimizer, guard=args.guard)
-    opt_shardings = None
-    if args.zero1:
-        state = state._replace(opt_state=zero1_lib.shard_state(
-            state.opt_state, params, mesh, pspecs=pspecs))
-        opt_shardings = zero1_lib.opt_shardings(
-            state.opt_state, params, mesh, pspecs=pspecs, zero1=True)
+    # Place the whole initial state on the mesh, in the layout the step
+    # returns (optimizer state pinned to ZeRO-1 shards or the param layout,
+    # counters replicated): a first step fed differently placed inputs than
+    # every later one would compile its phase twice.
+    opt_shardings = zero1_lib.opt_shardings(
+        state.opt_state, params, mesh, pspecs=pspecs, zero1=args.zero1)
+    replicated = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    state = state._replace(
+        opt_state=jax.device_put(state.opt_state, opt_shardings),
+        step=jax.device_put(state.step, replicated),
+        guard=jax.device_put(state.guard, replicated))
     # One jitted step per phase name. Under staggered that is one mixed
     # phase per step-residue (stagger:0..P-1); 'block' and 'full' ride
     # along (jit is lazy, unused variants never compile) so the guard's
     # forced-full escalation keeps its synchronous 'full' variant.
     phases = tuple(dict.fromkeys((*schedule.phases(), "block", "full")))
+    param_shardings = sh.named(mesh, pspecs)
     fns = make_train_step_fns(cfg, optimizer, ctx, opt_shardings=opt_shardings,
+                              param_shardings=param_shardings,
                               guard=guard_cfg, phases=phases)
     pipe_src = SyntheticLM(cfg, args.batch, args.seq, seed=args.seed)
     pipe = iter(pipe_src)
@@ -446,7 +486,8 @@ def main():
         if key not in fault_fns:
             fault_fns[key] = make_train_step_fns(
                 cfg, optimizer, ctx, opt_shardings=opt_shardings,
-                guard=guard_cfg, fault=fault, phases=phases)[phase]
+                param_shardings=param_shardings, guard=guard_cfg,
+                fault=fault, phases=phases)[phase]
         return fault_fns[key]
 
     # Run metadata: verified on resume so a wrong-arch/optimizer/mesh resume
@@ -499,7 +540,7 @@ def main():
                 ck_path, meta = found
                 r_params, r_opt, saved_step = checkpoint.restore(
                     ck_path, state.params, state.opt_state,
-                    shardings=sh.named(mesh, pspecs), opt_shardings=opt_shardings,
+                    shardings=param_shardings, opt_shardings=opt_shardings,
                     verify_checksums=False)  # latest_valid already verified
                 state = state._replace(
                     params=r_params, opt_state=r_opt,
@@ -535,7 +576,10 @@ def main():
         # on skips that happened before the preemption.
         escalator._last_total = int(state.guard.skipped)
 
+    losses, phases_run, step_s, compile_s = [], [], [], []
+
     def finish(status):
+        clock.stop()
         if drift_mon is not None:
             drift_mon.report()
         if prof_window is not None and profiling[0]:
@@ -545,7 +589,14 @@ def main():
                   wall_s=round(time.time() - t0, 1), status=status,
                   counters=dict(bus.counters))
         bus.close()
+        return TrainResult(
+            losses=[float(v) for v in jax.device_get(losses)],
+            phases=phases_run, step_s=step_s, compile_s=compile_s,
+            device=device, status=status, state=state, step_fns=fns,
+            batch=batch)
 
+    clock = compile_cache.CompileClock().start()
+    batch = None
     t0 = time.time()
     forced_full = False
     profiling = [False]
@@ -575,7 +626,12 @@ def main():
                   sync=((lambda: jax.block_until_ready(state))
                         if args.obs_block else None),
                   step=step, phase=phase, residue=residue, due=due) as sp:
+            compiled_before = clock.seconds
             state, metrics = step_fn(phase, fault)(state, batch)
+        losses.append(metrics["loss"])
+        phases_run.append(phase)
+        step_s.append(sp.dur_s)
+        compile_s.append(clock.seconds - compiled_before)
         if drift_mon is not None:
             drift_mon.observe(step, phase, sp.dur_s)
         if prof_window is not None and profiling[0] and step == prof_window[1] - 1:
@@ -617,7 +673,7 @@ def main():
                       "consecutive_skips": escalator.consecutive})
             finish("abort")
             sys.exit(3)
-    finish("ok")
+    return finish("ok")
 
 
 if __name__ == "__main__":

@@ -56,6 +56,12 @@ def cast_tree(tree, dtype):
     )
 
 
+def _pin(tree, shardings):
+    if shardings is None:
+        return tree
+    return jax.lax.with_sharding_constraint(tree, shardings)
+
+
 def train_step(
     state: TrainState,
     batch: dict,
@@ -68,6 +74,7 @@ def train_step(
     accum_steps: int = 1,
     bf16_grads: bool = False,
     opt_shardings=None,
+    param_shardings=None,
     guard=None,
     fault=None,
 ):
@@ -87,6 +94,13 @@ def train_step(
     is pinned to it with a sharding constraint so ZeRO-1 momentum shards
     survive the compiled step instead of being replicated at the
     partitioner's whim.
+
+    ``param_shardings``: optional pytree of NamedShardings matching the
+    params. The new params are pinned to it, so they leave the step in the
+    layout they entered with: under ZeRO-1 the update is computed on
+    ``data`` shards, and without the pin the partitioner keeps the new
+    params sharded that way, so the next step sees another input layout
+    (and compiles again) and its forward gathers the params.
 
     ``guard``: optional :class:`repro.training.resilience.GuardConfig`.
     Wraps the optimizer apply in the in-graph health check + ``lax.cond``
@@ -161,6 +175,7 @@ def train_step(
             from repro.distributed import zero1 as zero1_lib
 
             new_opt_state = zero1_lib.constrain(new_opt_state, opt_shardings)
+        new_params = _pin(new_params, param_shardings)
         metrics = dict(metrics)
         metrics["grad_norm"] = jnp.sqrt(grad_sq_norm)
         metrics["healthy"] = healthy.astype(jnp.int32)
@@ -175,7 +190,7 @@ def train_step(
         from repro.distributed import zero1 as zero1_lib
 
         new_opt_state = zero1_lib.constrain(new_opt_state, opt_shardings)
-    new_params = apply_updates(state.params, updates)
+    new_params = _pin(apply_updates(state.params, updates), param_shardings)
     metrics = dict(metrics)
     metrics["grad_norm"] = jnp.sqrt(
         sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(grads))
@@ -184,8 +199,9 @@ def train_step(
 
 
 def make_train_step_fns(cfg, optimizer, ctx, donate=True, compute_dtype=jnp.bfloat16,
-                        accum_steps: int = 1, opt_shardings=None, guard=None,
-                        fault=None, phases=("block", "full")):
+                        accum_steps: int = 1, opt_shardings=None,
+                        param_shardings=None, guard=None, fault=None,
+                        phases=("block", "full")):
     """Returns {phase: jitted fn} over (state, batch), one per phase name.
 
     ``phases`` defaults to the synchronous pair; a staggered launcher passes
@@ -204,6 +220,7 @@ def make_train_step_fns(cfg, optimizer, ctx, donate=True, compute_dtype=jnp.bflo
             compute_dtype=compute_dtype,
             accum_steps=accum_steps,
             opt_shardings=opt_shardings,
+            param_shardings=param_shardings,
             guard=guard,
             fault=fault,
         )

@@ -15,6 +15,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, functools
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.launch.dryrun import parse_collectives
 from repro.models.model import init_params
@@ -26,7 +27,7 @@ import dataclasses
 
 cfg = get_config("granite-8b").reduced()
 cfg = dataclasses.replace(cfg, d_model=256, d_ff=512, vocab_size=512, num_layers=2)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ctx = sh.make_ctx(cfg, mesh, global_batch=4)
 
 a_params = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))

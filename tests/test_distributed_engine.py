@@ -21,6 +21,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, functools, json
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
@@ -35,7 +36,7 @@ from repro.training.train_step import TrainState, init_train_state, make_train_s
 
 cfg = get_config("granite-8b").reduced()
 cfg = dataclasses.replace(cfg, d_model=256, d_ff=512, vocab_size=512, num_layers=2)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ctx = sh.make_ctx(cfg, mesh, global_batch=4)
 
 params = init_params(jax.random.PRNGKey(0), cfg)

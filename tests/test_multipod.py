@@ -365,6 +365,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 import json
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import LeafSpec, compile_program, muon
@@ -379,7 +380,7 @@ from repro.distributed import zero1 as z1
 out = {}
 
 # ---------------- (2,2,2) hierarchical mesh over 8 of the devices --------
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"),
                      devices=jax.devices()[:8])
 layout = {
     # 3 layers over pod*data=4 -> flatten fallback engages under zero1
@@ -490,7 +491,7 @@ for phase in ("block", "full"):
 out["flatten"]["audits"] = audits
 
 # ---------------- granite shape: 36 layers / 16-way data axis -----------
-mesh16 = jax.make_mesh((16, 1), ("data", "model"), devices=jax.devices())
+mesh16 = make_mesh((16, 1), ("data", "model"), devices=jax.devices())
 tree = {"layers": jax.random.normal(jax.random.PRNGKey(9), (36, 8, 16))}
 tree = jax.device_put(tree, NamedSharding(mesh16, P(None, None, None)))
 grads16 = jax.tree.map(lambda p: 0.1 * p, tree)

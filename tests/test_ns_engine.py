@@ -146,9 +146,9 @@ def test_plan_strategy_decides_per_shape(monkeypatch):
         dispatch.plan_strategy((4, 64, 128), "pallas")
 
 
-# (128, n): fused working set = 1024*n_p + 128 KiB — chosen to fit the full
-# 12 MiB budget but NOT the pipeline-reserved one (10 MiB).
-_EDGE_SHAPE = (128, 11008)
+# (128, n): counted fused VMEM = 2048*n_p + 320 KiB — chosen to fit the full
+# 16 MiB budget but NOT the pipeline-reserved one (14 MiB).
+_EDGE_SHAPE = (128, 7936)
 
 
 def test_plan_strategy_pipeline_vmem_budget(monkeypatch):

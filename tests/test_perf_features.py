@@ -16,6 +16,7 @@ import pytest
 from conftest import tiny_cfg
 from repro.configs import get_config
 from repro.core import muon, muon_full
+from repro.launch.mesh import make_mesh
 from repro.models.model import decode_step, init_cache, init_params, loss_fn
 from repro.models.transformer import forward
 from repro.training.train_step import TrainState, init_train_state, train_step
@@ -78,7 +79,7 @@ def test_train_step_accum_runs(key):
 def test_layer_shard_full_ns_single_device_math(key):
     """The layer_shard program CommOp on a 1-device mesh must equal the
     plain full step (padding + resharding are numerically inert)."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     g = jax.random.normal(key, (3, 16, 24))  # stacked "layers"
     plain = muon_full(0.1, rms_match=False)
     dist = muon(0.1, 0.1, period=1, rms_match=False, layer_shard=(mesh, "data"))

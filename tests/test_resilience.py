@@ -332,6 +332,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import adamw, combine, label_tree, muon
@@ -342,7 +343,7 @@ from repro.distributed import (
 from repro.distributed import zero1 as z1
 from repro.training.resilience import GuardConfig, guarded_update, init_guard_state
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 key = jax.random.PRNGKey(0)
 params = {
     "stack_col": jax.random.normal(key, (8, 16, 32)),

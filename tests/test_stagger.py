@@ -40,6 +40,7 @@ from repro.core.blocking import BlockSpec2D
 from repro.core.combine import apply_updates
 from repro.core.muon import StaggerSchedule, phase_for_step
 from repro.distributed import assign_stagger_offsets, make_engine, plan_comm
+from repro.launch.mesh import make_mesh
 
 
 def fake_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")):
@@ -201,7 +202,7 @@ def test_plan_offsets_match_program_offsets():
 # ------------------------------------------------------------ muon glue
 
 def _one_dev_setup():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = {
         "wa": jax.random.normal(jax.random.PRNGKey(0), (32, 64)),
         "wb": jax.random.normal(jax.random.PRNGKey(1), (32, 32)),
@@ -300,6 +301,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import muon
@@ -314,7 +316,7 @@ from repro.distributed import zero1 as z1
 
 PERIOD = 3
 out = {}
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 layout = {
     "stack": ((3, 16, 32), P(None, None, "model"),     BlockSpec2D(1, 2)),
     "wq":    ((16, 32),    P(None, "model"),           BlockSpec2D(1, 2)),

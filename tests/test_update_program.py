@@ -28,6 +28,7 @@ from repro.core import (
 )
 from repro.core import program as program_lib
 from repro.kernels import dispatch
+from repro.launch.mesh import make_mesh
 
 
 # --------------------------------------------------------------- reference
@@ -122,7 +123,7 @@ def test_program_matches_seed_per_leaf(phase, dtype, bucketing):
 @pytest.mark.parametrize("phase", ["block", "full"])
 def test_layer_shard_program_matches_seed(phase, key):
     """The layer_shard CommOp changes placement, never numerics."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     params, grads, blocks = make_tree(jnp.float32)
     opt = muon(LR, momentum=MU, weight_decay=WD, block_specs=blocks,
                layer_shard=(mesh, "data"))
@@ -141,7 +142,7 @@ def test_shard_map_engine_program_matches_seed(phase, bucketing):
     tests/test_distributed_engine.py."""
     from repro.distributed import make_engine
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params, grads, blocks = make_tree(jnp.float32)
     pspecs = jax.tree.map(lambda p: P(*(None,) * p.ndim), params)
     engine = make_engine(params, pspecs, mesh)
@@ -480,6 +481,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import LeafSpec, compile_program, muon
@@ -489,7 +491,7 @@ from repro.distributed import (
 )
 from repro.distributed import zero1 as z1
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 layout = {
     "wq":    ((64, 128),    P(None, "model"),       BlockSpec2D(1, 4)),
     "wo":    ((128, 64),    P("model", None),       BlockSpec2D(4, 1)),

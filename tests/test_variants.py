@@ -310,10 +310,15 @@ def test_turbo_muon_reduced_k_orthogonalizes_as_well():
 # ------------------------------------------ NorMuon kernel: bitwise parity
 
 @pytest.mark.parametrize("refresh", [True, False])
-@pytest.mark.parametrize("shape", [(1, 8, 128), (2, 10, 17), (3, 16, 130)])
+@pytest.mark.parametrize("shape", [
+    (1, 8, 128), (2, 10, 17), (3, 16, 130),
+    (1, 200, 6144),   # three row tiles of 72 (normuon.row_tile), last padded
+    (2, 30, 16384),   # two row tiles of 16, the last one padded
+])
 def test_normuon_kernel_bitwise_vs_reference(refresh, shape):
     """Interpret-mode Pallas kernel == jnp reference BIT FOR BIT: both run
-    the same fp32 math on identically padded operands."""
+    the same fp32 math on identically padded operands, whatever the row
+    tiling of the kernel's grid."""
     k1, k2 = jax.random.split(jax.random.PRNGKey(3))
     x = jax.random.normal(k1, shape, jnp.float32)
     v = jnp.abs(jax.random.normal(k2, (*shape[:-1], 1), jnp.float32))
