@@ -604,7 +604,8 @@ def main(argv=None) -> TrainResult:
         if prof_window is not None and step == prof_window[0]:
             jax.profiler.start_trace(args.profile_dir)
             profiling[0] = True
-        batch = {k: jnp.asarray(v) for k, v in next(pipe).items()}
+        with span(bus, "data", step=step):
+            batch = {k: jnp.asarray(v) for k, v in next(pipe).items()}
         phase = schedule.phase_for(step) if args.optimizer != "adamw" else "block"
         if forced_full and args.optimizer != "adamw":
             phase = "full"

@@ -51,7 +51,8 @@ def loss_fn(
     )
     if cfg.vision_tokens:
         logits = logits[:, cfg.vision_tokens :, :]
-    ce = cross_entropy(logits, batch["labels"])
+    with jax.named_scope("model.head"):
+        ce = cross_entropy(logits, batch["labels"])
     loss = ce
     metrics = {"ce": ce}
     if cfg.num_experts:

@@ -13,6 +13,13 @@ Modes:
 
 Embeddings are vocab-parallel (Megatron-style); logits stay vocab-sharded
 into the loss (logsumexp + label-gather need only tiny collectives).
+
+Each layer of the step runs under a ``jax.named_scope`` that a profiler
+trace reads back through the HLO ``op_name`` metadata: ``model.embed``
+(the lookup), ``model.attn`` and ``model.mlp`` (each sublayer with its
+norms and residual add) and ``model.head`` (final norm and logits; the
+loss adds the cross-entropy). SSM, cross-attention and encoder layers
+carry no scope.
 """
 
 from __future__ import annotations
@@ -237,14 +244,15 @@ def decoder_layer(
     hybrid = cfg.arch_type == "hybrid"
 
     if has_attn and has_ssm and hybrid:
-        h = rms_norm(x, norms["attn_norm"])
-        attn_out, new_kv = attention_block(
-            h, layer["attn"], positions=positions,
-            window=None if ring else window,
-            kv_cache=kv_cache, cache_index=cache_index, kv_len=kv_len,
-            causal=not ring,
-            **_attn_kwargs(cfg, inv_freq, ctx),
-        )
+        with jax.named_scope("model.attn"):
+            h = rms_norm(x, norms["attn_norm"])
+            attn_out, new_kv = attention_block(
+                h, layer["attn"], positions=positions,
+                window=None if ring else window,
+                kv_cache=kv_cache, cache_index=cache_index, kv_len=kv_len,
+                causal=not ring,
+                **_attn_kwargs(cfg, inv_freq, ctx),
+            )
         if mode == "decode":
             ssm_out, new_ssm = ssm_lib.ssm_decode_step(
                 h, ssm_state, layer["ssm"], ssm_dims(cfg)
@@ -261,17 +269,18 @@ def decoder_layer(
         )
         x = x + combined
     elif has_attn:
-        h = rms_norm(x, norms["attn_norm"])
-        attn_out, new_kv = attention_block(
-            h, layer["attn"], positions=positions,
-            window=None if ring else window,
-            kv_cache=kv_cache, cache_index=cache_index, kv_len=kv_len,
-            causal=(cfg.arch_type != "encoder") and not ring,
-            **_attn_kwargs(cfg, inv_freq, ctx),
-        )
-        if cfg.use_post_norms:
-            attn_out = rms_norm(attn_out, norms["post_attn_norm"])
-        x = x + attn_out
+        with jax.named_scope("model.attn"):
+            h = rms_norm(x, norms["attn_norm"])
+            attn_out, new_kv = attention_block(
+                h, layer["attn"], positions=positions,
+                window=None if ring else window,
+                kv_cache=kv_cache, cache_index=cache_index, kv_len=kv_len,
+                causal=(cfg.arch_type != "encoder") and not ring,
+                **_attn_kwargs(cfg, inv_freq, ctx),
+            )
+            if cfg.use_post_norms:
+                attn_out = rms_norm(attn_out, norms["post_attn_norm"])
+            x = x + attn_out
     elif has_ssm:  # pure SSM (mamba2)
         h = rms_norm(x, norms["ssm_norm"])
         if mode == "decode":
@@ -295,24 +304,26 @@ def decoder_layer(
         x = x + cross_out
 
     if "moe" in layer:
-        h = rms_norm(x, norms["mlp_norm"])
-        out = moe_block(
-            h, layer["moe"],
-            top_k=cfg.top_k,
-            capacity_factor=cfg.capacity_factor,
-            router_style=cfg.router_style,
-            mesh=ctx.mesh,
-            data_axes=ctx.batch_axes,
-            model_axis=ctx.model_axis,
-        )
-        aux = jnp.stack([out.load_balance_loss, out.router_z_loss])
-        x = x + out.y
+        with jax.named_scope("model.mlp"):
+            h = rms_norm(x, norms["mlp_norm"])
+            out = moe_block(
+                h, layer["moe"],
+                top_k=cfg.top_k,
+                capacity_factor=cfg.capacity_factor,
+                router_style=cfg.router_style,
+                mesh=ctx.mesh,
+                data_axes=ctx.batch_axes,
+                model_axis=ctx.model_axis,
+            )
+            aux = jnp.stack([out.load_balance_loss, out.router_z_loss])
+            x = x + out.y
     elif "mlp" in layer:
-        h = rms_norm(x, norms["mlp_norm"])
-        mlp_out = _mlp_apply(h, layer["mlp"], cfg)
-        if cfg.use_post_norms:
-            mlp_out = rms_norm(mlp_out, norms["post_mlp_norm"])
-        x = x + mlp_out
+        with jax.named_scope("model.mlp"):
+            h = rms_norm(x, norms["mlp_norm"])
+            mlp_out = _mlp_apply(h, layer["mlp"], cfg)
+            if cfg.use_post_norms:
+                mlp_out = rms_norm(mlp_out, norms["post_mlp_norm"])
+            x = x + mlp_out
 
     return x, new_kv, new_ssm, aux
 
@@ -361,7 +372,8 @@ def forward(
     encoder_frames: (B, S_enc, D) whisper frame embeddings (audio arch).
     With mode='prefill', also returns the cache pytree for decode.
     """
-    x = _embed(params, tokens, cfg)
+    with jax.named_scope("model.embed"):
+        x = _embed(params, tokens, cfg)
     if extra_embeds is not None:
         x = jnp.concatenate([extra_embeds.astype(x.dtype), x], axis=1)
     seq = x.shape[1]
@@ -414,8 +426,9 @@ def forward(
         body, x, (layers, flags), unroll=True if scan_unroll() else 1
     )
     x = _seq_shard(x, ctx)
-    x = rms_norm(x, params["final_norm"])
-    logits = _logits(params, x, cfg)
+    with jax.named_scope("model.head"):
+        x = rms_norm(x, params["final_norm"])
+        logits = _logits(params, x, cfg)
     aux = {"load_balance": outs["aux"][:, 0].sum(), "z_loss": outs["aux"][:, 1].sum()}
     if prefill:
         cache = {k: v for k, v in outs.items() if k != "aux"}

@@ -9,11 +9,11 @@ engine, and the benchmarks:
   list for tests). Counters (guard skips, escalations, checkpoint
   fallbacks, NS kernel launches) accumulate on the bus and ride out in the
   ``run_end`` record.
-* :mod:`repro.obs.spans` — host-side span timers (step / checkpoint-save /
-  resume, nested with parent attribution) plus the ``jax.named_scope``
-  stage annotations the shard_map engine wraps around each
-  :class:`~repro.core.program.PipelineStage`, so a captured profiler trace
-  reads against ``UpdateProgram.summary()``.
+* :mod:`repro.obs.spans` — host-side span timers (step / data /
+  checkpoint-save / resume, nested with parent attribution), each also a
+  ``jax.profiler.TraceAnnotation``, so a captured profiler trace shows
+  them beside the device ops (whose layer and stage names are
+  ``jax.named_scope`` calls in the program: docs/observability.md).
 * :mod:`repro.obs.drift` — the plan-vs-runtime drift monitor: joins
   ``CommPlan.predicted_by_link`` (and, when available, the pipeline
   schedule's exposed bytes) against measured block/full step wall times,
@@ -52,5 +52,4 @@ from repro.obs.spans import (  # noqa: F401
     Span,
     percentiles,
     span,
-    stage_scope,
 )

@@ -1,23 +1,23 @@
-"""Span-based timing: host-side timers + device-trace stage annotations.
+"""Span-based timing: host-side timers that also mark the profiler trace.
 
-Two complementary layers:
+:func:`span` is a host-side context manager timing step / phase /
+checkpoint-save / resume / data regions with ``time.perf_counter``. Spans
+nest via a thread-local stack; a child records its parent's name so
+``scripts/obs_report.py`` can attribute e.g. ``checkpoint.save`` time
+inside a ``step`` span. Each span emits one ``{"event": "span"}`` record
+on exit, and holds a ``jax.profiler.TraceAnnotation`` of its name open
+while it times, so a ``--profile-steps`` capture shows the host's spans
+beside the device ops on the trace's clock (with no trace running the
+annotation records nothing). Host wall times include device time only up
+to dispatch — pass a ``sync`` callable (e.g. ``jax.block_until_ready``
+over the step outputs, ``train.py --obs-block``) when accurate per-step
+device wall times are wanted; by default nothing is synchronized and
+instrumentation adds no device round-trips.
 
-* :func:`span` — a host-side context manager timing step / phase /
-  checkpoint-save / resume regions with ``time.perf_counter``. Spans nest
-  via a thread-local stack; a child records its parent's name so
-  ``scripts/obs_report.py`` can attribute e.g. ``checkpoint.save`` time
-  inside a ``step`` span. Each span emits one ``{"event": "span"}``
-  record on exit. Host wall times include device time only up to
-  dispatch — pass a ``sync`` callable (e.g. ``jax.block_until_ready``
-  over the step outputs, ``train.py --obs-block``) when accurate
-  per-step device wall times are wanted; by default nothing is
-  synchronized and instrumentation adds no device round-trips.
-* :func:`stage_scope` — a ``jax.named_scope`` wrapper the shard_map
-  engine puts around each pipeline stage (gather / ns / writeback per
-  bucket). ``named_scope`` only attaches names to the traced ops (HLO
-  metadata + profiler ``TraceAnnotation`` rows), so instrumented programs
-  stay bitwise-identical; a trace captured via ``--profile-steps`` reads
-  directly against ``UpdateProgram.summary()`` stage indices.
+Device-side names are ``jax.named_scope`` calls in the program itself: the
+model's layers (``models/transformer.py``), the optimizer apply
+(``training/train_step.py``) and the comm engine's stages
+(``distributed/engine.py``); docs/observability.md lists them.
 """
 
 from __future__ import annotations
@@ -67,7 +67,8 @@ def span(
     sync: Callable[[], Any] | None = None,
     **attrs: Any,
 ) -> Iterator[Span]:
-    """Time a region and emit a ``span`` record on exit.
+    """Time a region, mark it in the profiler trace, and emit a ``span``
+    record on exit.
 
     ``sync`` (if given) runs inside the timed region just before the clock
     stops — the hook for ``jax.block_until_ready`` when the caller wants
@@ -77,13 +78,16 @@ def span(
     """
     sp = Span(name=name, attrs=dict(attrs), parent=current_span())
     _stack().append(sp)
-    t0 = time.perf_counter()
     try:
-        yield sp
+        with jax.profiler.TraceAnnotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield sp
+            finally:
+                if sync is not None:
+                    sync()
+                sp.dur_s = time.perf_counter() - t0
     finally:
-        if sync is not None:
-            sync()
-        sp.dur_s = time.perf_counter() - t0
         _stack().pop()
         if bus is not None:
             rec: dict[str, Any] = {"event": "span", "name": name, "dur_s": round(sp.dur_s, 6)}
@@ -99,15 +103,6 @@ def record_span(bus: bus_lib.Bus | None, name: str, dur_s: float, **attrs: Any) 
     if bus is None:
         return
     bus.emit({"event": "span", "name": name, "dur_s": round(float(dur_s), 6), **attrs})
-
-
-def stage_scope(name: str):
-    """``jax.named_scope`` for a pipeline stage — trace-time only, no ops.
-
-    Names follow ``muonbp.<phase>.s<stage>.<gather|ns|writeback>`` so a
-    profiler trace lines up with ``PipelineSchedule.describe()`` rows.
-    """
-    return jax.named_scope(name)
 
 
 def parse_profile_window(spec: str) -> tuple[int, int]:

@@ -11,6 +11,11 @@ interprets its compiled ``UpdateProgram`` (core/program.py), so each of the
 two jitted step functions traces exactly one bucket schedule — the block
 step's zero-collective property and the full step's gather bytes are
 properties of the compiled artifact, asserted by the HLO audit.
+
+The optimizer apply (update, ZeRO-1 constraint, parameter update and pin)
+runs under the ``opt`` name scope, inside which the comm engine's
+``muonbp.<phase>...`` stage scopes nest; the model's layers carry their
+own scopes (models/transformer.py).
 """
 
 from __future__ import annotations
@@ -60,6 +65,14 @@ def _pin(tree, shardings):
     if shardings is None:
         return tree
     return jax.lax.with_sharding_constraint(tree, shardings)
+
+
+def _constrain_opt(opt_state, opt_shardings):
+    if opt_shardings is None:
+        return opt_state
+    from repro.distributed import zero1 as zero1_lib
+
+    return zero1_lib.constrain(opt_state, opt_shardings)
 
 
 def train_step(
@@ -167,15 +180,13 @@ def train_step(
         grad_sq_norm = sum(
             jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(grads)
         )
-        new_params, new_opt_state, new_guard, healthy = resilience.guarded_update(
-            optimizer, guard, grads, state.opt_state, state.params, gstate,
-            loss, grad_sq_norm, phase,
-        )
-        if opt_shardings is not None:
-            from repro.distributed import zero1 as zero1_lib
-
-            new_opt_state = zero1_lib.constrain(new_opt_state, opt_shardings)
-        new_params = _pin(new_params, param_shardings)
+        with jax.named_scope("opt"):
+            new_params, new_opt_state, new_guard, healthy = resilience.guarded_update(
+                optimizer, guard, grads, state.opt_state, state.params, gstate,
+                loss, grad_sq_norm, phase,
+            )
+            new_opt_state = _constrain_opt(new_opt_state, opt_shardings)
+            new_params = _pin(new_params, param_shardings)
         metrics = dict(metrics)
         metrics["grad_norm"] = jnp.sqrt(grad_sq_norm)
         metrics["healthy"] = healthy.astype(jnp.int32)
@@ -183,14 +194,12 @@ def train_step(
         metrics["ema_loss"] = resilience.debiased_ema(guard, new_guard)
         metrics["lr_scale"] = new_guard.lr_scale
         return TrainState(new_params, new_opt_state, state.step + 1, new_guard), metrics
-    updates, new_opt_state = optimizer.update(
-        grads, state.opt_state, state.params, phase
-    )
-    if opt_shardings is not None:
-        from repro.distributed import zero1 as zero1_lib
-
-        new_opt_state = zero1_lib.constrain(new_opt_state, opt_shardings)
-    new_params = _pin(apply_updates(state.params, updates), param_shardings)
+    with jax.named_scope("opt"):
+        updates, new_opt_state = optimizer.update(
+            grads, state.opt_state, state.params, phase
+        )
+        new_opt_state = _constrain_opt(new_opt_state, opt_shardings)
+        new_params = _pin(apply_updates(state.params, updates), param_shardings)
     metrics = dict(metrics)
     metrics["grad_norm"] = jnp.sqrt(
         sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(grads))

@@ -167,6 +167,46 @@ def test_span_sync_runs_inside_clock():
     assert calls == [1] and sp.dur_s is not None
 
 
+@pytest.fixture
+def annotations(monkeypatch):
+    """Names passed to ``jax.profiler.TraceAnnotation``, in order."""
+    names = []
+
+    class Record:
+        def __init__(self, name, **kwargs):
+            names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Record)
+    return names
+
+
+def test_span_marks_the_profiler_trace(annotations):
+    with span(None, "step", step=0):
+        with span(None, "checkpoint.save"):
+            pass
+    assert annotations == ["step", "checkpoint.save"]
+
+
+def test_launcher_annotates_step_and_data_spans(annotations, monkeypatch, tmp_path):
+    """A 2-step CPU run of the launcher writes its ``step`` spans and the
+    ``data`` spans around each batch's preparation to the profiler trace."""
+    from repro.launch import compile_cache, train
+
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    run = train.main(["--arch", "muonbp-960m", "--reduced", "--steps", "2", "--batch", "2",
+                      "--seq", "32", "--optimizer", "muonbp", "--period", "2",
+                      "--drift-threshold", "0"])
+    assert run.status == "ok"
+    assert annotations.count("step") == 2 and annotations.count("data") == 2
+    assert annotations.index("data") < annotations.index("step")
+
+
 def test_percentiles_nearest_rank():
     vals = list(range(1, 101))  # 1..100
     p = percentiles(vals)
