@@ -1,12 +1,23 @@
 """Shared model layers: RMSNorm, RoPE, GQA attention, gated MLPs.
 
-Attention is a pure-JAX flash/online-softmax implementation (lax.scan over
-KV blocks, fp32 accumulators): full-sequence training at 4k and prefill at
-32k would otherwise materialize O(S^2) score tensors that cannot fit HBM.
-Supports causal masking, sliding windows (mixtral/gemma2/hymba), attention
-logit softcapping (gemma2), cross-attention (whisper), and KV-length masking
-(decode with a partially filled cache). Decode (Sq==1) uses a direct path —
-one token's scores over the cache are cheap and GSPMD shards them cleanly.
+Attention takes one of two paths (``flash_attention``):
+
+* Full-sequence causal self-attention on a TPU (training, and prefill
+  without a cache) runs on the Pallas flash kernel of
+  ``repro.kernels.attention``: bf16 operands, fp32 softmax, blocks above
+  the diagonal skipped. It applies only where the call shows it: no
+  window, no KV-length mask, no soft cap, query offset the literal 0,
+  Sq == Skv, a head size that is a multiple of 128, a sequence the
+  kernel's blocks divide, and under a mesh the ``head`` layout with heads
+  and batch that the mesh divides.
+* Every other call runs a pure-JAX flash/online-softmax scan (lax.scan
+  over KV blocks, fp32 accumulators), which never materializes the O(S^2)
+  scores: sliding windows (mixtral/gemma2/hymba), attention logit
+  softcapping (gemma2), cross-attention and the non-causal encoder
+  (whisper), prefill into a cache at an offset, KV-length masking (decode
+  with a partially filled cache), and every backend but the TPU. Decode
+  (Sq==1) uses a direct path — one token's scores over the cache are
+  cheap and GSPMD shards them cleanly.
 """
 
 from __future__ import annotations
@@ -15,6 +26,8 @@ import os
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import attention as attention_kernel
 
 NEG_INF = -1e30
 
@@ -136,16 +149,32 @@ def flash_attention(
     q_offset: jax.Array | int = 0,
     kv_len: jax.Array | int | None = None,
     block_k: int = 1024,
+    mesh: jax.sharding.Mesh | None = None,
+    batch_axes: tuple[str, ...] = (),
+    model_axis: str | None = None,
+    q_layout: str = "head",
+    kv_layout: str = "head",
 ) -> jax.Array:
     """Online-softmax attention.
 
     q: (B, Sq, Hq, hd); k, v: (B, Skv, Hkv, hd) with Hq % Hkv == 0 (GQA).
     Returns (B, Sq, Hq, hd) in q.dtype. Scores/accumulators are fp32.
+    Where ``attention_kernel.use_kernel`` holds, the Pallas kernel computes
+    it (under ``mesh``, sharded over ``batch_axes`` and ``model_axis``);
+    otherwise the scan below, whose blocks are ``block_k`` keys.
     """
     batch, sq, hq, hd = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     groups = hq // hkv
     scale = hd ** -0.5
+
+    if attention_kernel.use_kernel(
+        q.shape, k.shape, causal=causal, window=window, attn_softcap=attn_softcap,
+        q_offset=q_offset, kv_len=kv_len, mesh=mesh, batch_axes=batch_axes,
+        model_axis=model_axis, q_layout=q_layout, kv_layout=kv_layout,
+    ):
+        return attention_kernel.causal_attention(
+            q, k, v, mesh=mesh, batch_axes=batch_axes, model_axis=model_axis)
 
     if sq == 1:
         return _decode_attention(
@@ -265,12 +294,17 @@ def attention_block(
     block_k: int = 1024,
     q_layout: str = "head",
     kv_layout: str = "head",
+    mesh: jax.sharding.Mesh | None = None,
+    batch_axes: tuple[str, ...] = (),
+    model_axis: str | None = None,
 ):
     """Full attention sub-block: projections + rope + attention + out-proj.
 
     Returns (out, new_kv_cache). With ``kv_cache`` given, the fresh K/V are
     written at ``cache_index`` and attention runs over the whole cache.
     With ``cross_kv`` (B, S_enc, D) this is cross-attention (no cache/rope).
+    ``mesh``, ``batch_axes`` and ``model_axis`` say how the kernel path of
+    ``flash_attention`` shards.
     """
     batch, sq, _ = x.shape
     kv_src = cross_kv if cross_kv is not None else x
@@ -303,6 +337,11 @@ def attention_block(
         q_offset=q_offset,
         kv_len=kv_len,
         block_k=block_k,
+        mesh=mesh,
+        batch_axes=batch_axes,
+        model_axis=model_axis,
+        q_layout=q_layout,
+        kv_layout=kv_layout,
     )
     out = merge_heads(out, q_layout) @ params["wo"]
     return out, new_cache

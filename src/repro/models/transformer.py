@@ -54,7 +54,7 @@ class ShardCtx(NamedTuple):
     q_layout: str = "head"    # 'head' | 'hd' (see layers.split_heads)
     kv_layout: str = "head"
     batch_axes: tuple = ()    # mesh axes sharding the batch dim of this run
-    flash_block_k: int = 1024  # flash-attention KV block (fp32 score memory)
+    flash_block_k: int = 1024  # KV block of the jnp attention scan (fp32 score memory)
 
 
 
@@ -213,6 +213,9 @@ def _attn_kwargs(cfg, inv_freq, ctx: ShardCtx = ShardCtx()):
         q_layout=ctx.q_layout,
         kv_layout=ctx.kv_layout,
         block_k=ctx.flash_block_k,
+        mesh=ctx.mesh,
+        batch_axes=ctx.batch_axes,
+        model_axis=ctx.model_axis,
     )
 
 
@@ -242,13 +245,17 @@ def decoder_layer(
     has_attn = "attn" in layer
     has_ssm = "ssm" in layer
     hybrid = cfg.arch_type == "hybrid"
+    # A global layer's scanned NO_WINDOW masks nothing; pass None, which
+    # tells attention that the layer is global.
+    if ring or cfg.attention_pattern == "full":
+        window = None
 
     if has_attn and has_ssm and hybrid:
         with jax.named_scope("model.attn"):
             h = rms_norm(x, norms["attn_norm"])
             attn_out, new_kv = attention_block(
                 h, layer["attn"], positions=positions,
-                window=None if ring else window,
+                window=window,
                 kv_cache=kv_cache, cache_index=cache_index, kv_len=kv_len,
                 causal=not ring,
                 **_attn_kwargs(cfg, inv_freq, ctx),
@@ -273,7 +280,7 @@ def decoder_layer(
             h = rms_norm(x, norms["attn_norm"])
             attn_out, new_kv = attention_block(
                 h, layer["attn"], positions=positions,
-                window=None if ring else window,
+                window=window,
                 kv_cache=kv_cache, cache_index=cache_index, kv_len=kv_len,
                 causal=(cfg.arch_type != "encoder") and not ring,
                 **_attn_kwargs(cfg, inv_freq, ctx),
